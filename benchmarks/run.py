@@ -954,7 +954,10 @@ def main() -> None:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=8")
+    from repro import compile_cache
     from repro.core import engine
+
+    compile_cache.enable()
 
     os.makedirs(RESULTS_DIR, exist_ok=True)
     results = {}

@@ -37,7 +37,7 @@ from .report import Finding
 __all__ = ["walk_jaxpr", "trace_and_walk", "default_relativize"]
 
 _IMPURE = frozenset({
-    "debug_callback", "io_callback", "pure_callback", "callback",
+    "debug_callback", "debug_print", "io_callback", "pure_callback", "callback",
     "outside_call", "host_callback_call",
 })
 # reductions that collapse an axis in linear space
@@ -62,7 +62,7 @@ def _user_frame(eqn):
     try:
         from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is not None:
             return frame.file_name, frame.start_line
     except Exception:

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 from . import engine
 from .goom import Goom, safe_log, to_goom
 
-__all__ = ["float_chain_survival", "goom_chain", "goom_chain_parallel", "ChainResult"]
+__all__ = ["float_chain_survival", "goom_chain", "goom_chain_parallel",
+           "chain_elements", "ChainResult"]
 
 
 class ChainResult(NamedTuple):
@@ -78,10 +79,14 @@ def goom_chain(key: jax.Array, d: int, n_steps: int, dtype=jnp.float32) -> Chain
     return ChainResult(steps, fro)
 
 
-def goom_chain_parallel(key: jax.Array, d: int, n_steps: int, dtype=jnp.float32) -> Goom:
-    """All prefix states in parallel via PSCAN(LMME) (paper eq. 24 machinery)."""
+def chain_elements(key: jax.Array, d: int, n_steps: int, dtype=jnp.float32) -> Goom:
+    """``[S_0, A_1, .., A_n]``: the chain's start state and its matrices."""
     k0, k1 = jax.random.split(key)
     mats = jax.random.normal(k1, (n_steps, d, d), dtype)
     s0 = jax.random.normal(k0, (1, d, d), dtype)
-    elems = to_goom(jnp.concatenate([s0, mats], axis=0))
-    return engine.cumulative_lmme(elems)
+    return to_goom(jnp.concatenate([s0, mats], axis=0))
+
+
+def goom_chain_parallel(key: jax.Array, d: int, n_steps: int, dtype=jnp.float32) -> Goom:
+    """All prefix states in parallel via PSCAN(LMME) (paper eq. 24 machinery)."""
+    return engine.cumulative_lmme(chain_elements(key, d, n_steps, dtype))

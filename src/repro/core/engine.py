@@ -318,7 +318,10 @@ def active_seq_shards() -> int:
     return 1 if shard is None else shard.n_shards
 
 
-def _impl(op: str, dtype, shapes: Optional[Tuple[int, ...]] = None) -> Callable:
+def _impl(op: str, dtype, shapes: Optional[Tuple[int, ...]] = None, *,
+          local: bool = False) -> Callable:
+    """The implementation of ``op`` for the current config; ``local`` for
+    code that already runs inside a shard body."""
     from repro.kernels import dispatch
 
     cfg = get_config()
@@ -326,7 +329,8 @@ def _impl(op: str, dtype, shapes: Optional[Tuple[int, ...]] = None) -> Callable:
                                         dtype=dtype)
     return dispatch.get_impl(op, resolved,
                              blocks=_block_overrides(cfg, op, resolved, shapes),
-                             shard=_resolved_shard(), shapes=shapes)
+                             shard=None if local else _resolved_shard(),
+                             shapes=shapes)
 
 
 # ---------------------------------------------------------------------------
@@ -443,8 +447,9 @@ def selective_reset_scan(
     the reset monoid has no identity element to pad with.
     """
     shard = _resolved_shard()
-    if shard is not None and a.shape[0] % shard.n_shards == 0 \
-            and a.shape[0] >= shard.n_shards:
+    in_shards = (shard is not None and a.shape[0] % shard.n_shards == 0
+                 and a.shape[0] >= shard.n_shards)
+    if in_shards:
         from repro.kernels import sharded as _sharded
 
         def assoc(fn, elems, axis=0, _spec=shard):
@@ -455,7 +460,7 @@ def selective_reset_scan(
 
     return _scan.selective_reset_scan(
         a, select_fn, reset_fn,
-        matmul=_impl("lmme", a.dtype),
+        matmul=_impl("lmme", a.dtype, local=in_shards),
         reset_only_state_compounds=reset_only_state_compounds,
         assoc_scan=assoc,
     )

@@ -37,6 +37,7 @@ __all__ = [
     "safe_log",
     "signed_exp",
     "finite_floor",
+    "closeness",
     "LOG_ZERO",
 ]
 
@@ -242,3 +243,29 @@ def goom_zeros(shape, dtype=jnp.float32, *, use_floor: bool = False) -> Goom:
 
 def goom_ones(shape, dtype=jnp.float32) -> Goom:
     return Goom(jnp.zeros(shape, dtype), jnp.ones(shape, dtype))
+
+
+def closeness(got: Goom, want: Goom, *, cancel_margin: float | None = 12.0) -> dict:
+    """How far two GOOM results lie apart, over the last axis (host numpy).
+
+    ``norm_err``: the largest difference of values divided by their row's
+    scale (the larger of the two rows' max log-magnitudes), well-posed
+    under catastrophic cancellation.  Unless ``cancel_margin`` is None,
+    entries within that many log-units of the row scale must also agree in
+    log-magnitude (``log_ok``: within 1e-3 + 1e-4 |log|) and sign
+    (``sign_mismatches``); below it an entry's log|sum| and even its sign
+    are ill-conditioned for any float method, the reference included."""
+    gl, gs = np.asarray(got.log_abs, np.float64), np.asarray(got.sign)
+    wl, ws = np.asarray(want.log_abs, np.float64), np.asarray(want.sign)
+    m = np.maximum(gl.max(-1, keepdims=True), wl.max(-1, keepdims=True))
+    m = np.where(np.isfinite(m), m, 0.0)  # an all-zero row compares as zeros
+    rep = {"finite": bool(np.isfinite(gl).all()),
+           "norm_err": float(np.abs(gs * np.exp(gl - m)
+                                    - ws * np.exp(wl - m)).max(initial=0.0))}
+    if cancel_margin is not None:
+        ok = wl > m - cancel_margin
+        log_err = np.abs(gl[ok] - wl[ok])
+        rep["max_log_err"] = float(log_err.max(initial=0.0))
+        rep["log_ok"] = bool((log_err <= 1e-3 + 1e-4 * np.abs(wl[ok])).all())
+        rep["sign_mismatches"] = int((gs[ok] != ws[ok]).sum())
+    return rep

@@ -75,6 +75,10 @@ def merge(base: BlockConfig, override: BlockConfig) -> BlockConfig:
 _TPU_LMME = BlockConfig(block_n=128, block_m=128, block_d=128)
 _TPU_DIAG = BlockConfig(block_t=256, block_c=512)
 _TPU_MAT = BlockConfig(block_t=128)
+# The B-carrying matrix scan holds twice the live tiles of the zero-B
+# (cumulative) one: at block_t=128 with 16x16 heads it overflows v5e's
+# default scoped VMEM; 64 compiles.
+_TPU_MAT_B = BlockConfig(block_t=64)
 # GPU tiles are warp-shaped: power-of-2, >=16 on dot dims so tl.dot maps to
 # tensor cores; the time tile is small because the in-kernel loop is
 # sequential (GPU grids are parallel CTAs — no cross-step grid carry).
@@ -87,16 +91,16 @@ _GPU_MAT = BlockConfig(block_t=32, num_warps=4, num_stages=1)
 _REF_MAT = BlockConfig(block_t=128)
 
 DEFAULTS: Dict[Tuple[str, str], BlockConfig] = {}
-for _backend, _lmme, _diag, _mat in (
-    ("pallas_tpu", _TPU_LMME, _TPU_DIAG, _TPU_MAT),
-    ("pallas_interpret", _TPU_LMME, _TPU_DIAG, _TPU_MAT),
-    ("pallas_gpu", _GPU_LMME, _GPU_DIAG, _GPU_MAT),
-    ("pallas_gpu_interpret", _GPU_LMME, _GPU_DIAG, _GPU_MAT),
-    ("xla_reference", BlockConfig(), BlockConfig(), _REF_MAT),
+for _backend, _lmme, _diag, _mat_b, _mat in (
+    ("pallas_tpu", _TPU_LMME, _TPU_DIAG, _TPU_MAT_B, _TPU_MAT),
+    ("pallas_interpret", _TPU_LMME, _TPU_DIAG, _TPU_MAT_B, _TPU_MAT),
+    ("pallas_gpu", _GPU_LMME, _GPU_DIAG, _GPU_MAT, _GPU_MAT),
+    ("pallas_gpu_interpret", _GPU_LMME, _GPU_DIAG, _GPU_MAT, _GPU_MAT),
+    ("xla_reference", BlockConfig(), BlockConfig(), _REF_MAT, _REF_MAT),
 ):
     DEFAULTS[("lmme", _backend)] = _lmme
     DEFAULTS[("diagonal_scan", _backend)] = _diag
-    DEFAULTS[("matrix_scan", _backend)] = _mat
+    DEFAULTS[("matrix_scan", _backend)] = _mat_b
     DEFAULTS[("cumulative_lmme", _backend)] = _mat
 
 

@@ -348,13 +348,21 @@ def get_impl(op: str, resolved: str, blocks: Optional[BlockConfig] = None,
     ``shard`` (a ``repro.kernels.sharded.ShardSpec`` or None) selects the
     sequence-sharded multi-device path: the local implementation above runs
     per device inside ``shard_map``, with a cross-shard LMME-monoid carry
-    combine stitching the time shards together.  ``lmme`` itself is not a
-    scan, so it ignores ``shard`` (it is already local inside shard bodies).
+    combine stitching the time shards together.  ``lmme`` is not a scan: it
+    runs inside a shard_map of its own, split over its leading batch dim
+    (a Pallas kernel cannot be partitioned automatically).  Code already
+    inside a shard body must take the local implementation (``shard=None``).
     """
     base, blocks = _make(op, resolved, blocks, shapes)
-    if shard is None or op == "lmme":
+    if shard is None:
         return base
     from . import sharded  # lazy: keeps single-device imports collective-free
+
+    if op == "lmme":
+        def f(a, b):
+            return sharded.seq_sharded_lmme(a, b, spec=shard, local_lmme=base)
+
+        return f
 
     if op == "diagonal_scan":
         def f(a, b, x0=None):

@@ -59,6 +59,47 @@ def _combine(e, l):
     return (a_l, a_s, b_l, b_s)
 
 
+def _tree_scan(combine, elems):
+    """Inclusive scan along the leading axis, paired exactly as
+    ``lax.associative_scan`` pairs it (odd/even recursion).
+
+    ``associative_scan`` itself does not lower in Mosaic: its strided slices
+    along time are refused.  Here the odd/even split is a reshape of the
+    time axis plus static slices, and the interleave a stack plus reshape,
+    which Mosaic lowers.  Keeping the reference's pairing keeps the kernel's
+    rounding that of the XLA reference: with signed operands at e±200,
+    compounds cancel inside the scan, and another pairing (a doubling scan)
+    moves results by more than the parity tests allow.
+    """
+    n = elems[0].shape[0]
+    if n < 2:
+        return elems
+    half = n // 2
+
+    def pairs(x):  # (x[0:2h:2], x[1:2h:2])
+        x2 = x[:2 * half].reshape((half, 2) + x.shape[1:])
+        return (jax.lax.index_in_dim(x2, 0, 1, keepdims=False),
+                jax.lax.index_in_dim(x2, 1, 1, keepdims=False))
+
+    evens, odds = zip(*(pairs(x) for x in elems))
+    odd_out = _tree_scan(combine, combine(evens, odds))  # positions 1, 3, ..
+    if n % 2:  # positions 2, 4, .., n - 1
+        later = tuple(jnp.concatenate([e[1:], x[n - 1:]]) for e, x in zip(evens, elems))
+        even_out = combine(odd_out, later)
+    elif half > 1:  # positions 2, 4, .., n - 2
+        even_out = combine(tuple(o[:-1] for o in odd_out),
+                           tuple(e[1:] for e in evens))
+    else:
+        even_out = None
+    out = []
+    for i, x in enumerate(elems):
+        ev = x[:1] if even_out is None else jnp.concatenate([x[:1], even_out[i]])
+        both = jnp.stack([ev[:half], odd_out[i]], axis=1)
+        both = both.reshape((2 * half,) + x.shape[1:])
+        out.append(jnp.concatenate([both, ev[half:]]) if n % 2 else both)
+    return tuple(out)
+
+
 def _scan_kernel(
     a_log_ref,
     a_sign_ref,
@@ -84,9 +125,8 @@ def _scan_kernel(
     bsn = b_sign_ref[...]
 
     # In-chunk inclusive scan of the (A, B) compound pairs.
-    a_star_l, a_star_s, b_star_l, b_star_s = jax.lax.associative_scan(
-        _combine, (al, asn, bl, bsn), axis=0
-    )
+    a_star_l, a_star_s, b_star_l, b_star_s = _tree_scan(
+        _combine, (al, asn, bl, bsn))
 
     # Fold the carried state:  x = A* ⊙ x_carry ⊕ B*.
     cl = carry_log_ref[...]  # (1, BC)

@@ -25,7 +25,7 @@ reshaped for a GPU launch.  Three time algorithms share the math:
   level — time_tiles × C elements, negligible); pass 2 folds each tile's
   incoming state in.  Total depth O(log T), two HBM round-trips.
 
-``num_warps`` / ``num_stages`` ride in via ``plgpu.TritonCompilerParams``.
+``num_warps`` / ``num_stages`` ride in via ``plgpu.CompilerParams``.
 Lowering: Pallas's Triton path on CUDA devices; ``interpret=True`` runs
 the identical bodies on CPU for CI parity (``pallas_gpu_interpret``).
 """
@@ -118,7 +118,7 @@ def goom_scan_gpu_kernel_call(
         in_specs=[ab_spec, ab_spec, ab_spec, ab_spec, x0_spec, x0_spec],
         out_specs=[ab_spec, ab_spec],
         out_shape=out_shape,
-        compiler_params=plgpu.TritonCompilerParams(
+        compiler_params=plgpu.CompilerParams(
             num_warps=num_warps, num_stages=num_stages),
         interpret=interpret,
     )(a_log, a_sign, b_log, b_sign, x0_log, x0_sign)
@@ -188,7 +188,7 @@ def goom_scan_gpu_tree_call(
         in_specs=[ab_spec, ab_spec, ab_spec, ab_spec, x0_spec, x0_spec],
         out_specs=[ab_spec, ab_spec],
         out_shape=out_shape,
-        compiler_params=plgpu.TritonCompilerParams(
+        compiler_params=plgpu.CompilerParams(
             num_warps=num_warps, num_stages=num_stages),
         interpret=interpret,
     )(a_log, a_sign, b_log, b_sign, x0_log, x0_sign)
@@ -275,7 +275,7 @@ def goom_scan_gpu_two_pass_call(
         jax.ShapeDtypeStruct((t, c), jnp.float32),
         jax.ShapeDtypeStruct((t, c), jnp.float32),
     ]
-    params = plgpu.TritonCompilerParams(
+    params = plgpu.CompilerParams(
         num_warps=num_warps, num_stages=num_stages)
 
     # Pass 1: every tile scanned independently (fully parallel grid).

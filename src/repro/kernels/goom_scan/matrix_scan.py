@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .goom_scan import _NEG, _lse2
+from .goom_scan import _NEG, _lse2, _tree_scan
 
 
 def _blmme(al, asn, bl, bsn):
@@ -52,6 +52,7 @@ def _blmme(al, asn, bl, bsn):
     prod = jax.lax.dot_general(
         ea, eb,
         dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,  # f32, as in kernels/lmme
         preferred_element_type=jnp.float32,
     )
     mag = jnp.abs(prod)
@@ -97,9 +98,8 @@ def _matrix_scan_kernel(
     bsn = b_sign_ref[0]
 
     # In-chunk inclusive scan of the (A, B) compound pairs (MXU combines).
-    a_star_l, a_star_s, b_star_l, b_star_s = jax.lax.associative_scan(
-        _mat_combine, (al, asn, bl, bsn), axis=0
-    )
+    a_star_l, a_star_s, b_star_l, b_star_s = _tree_scan(
+        _mat_combine, (al, asn, bl, bsn))
 
     # Fold the carried state:  X_t = A*_t ∘ X_carry ⊕ B*_t.
     bt = al.shape[0]
@@ -110,8 +110,10 @@ def _matrix_scan_kernel(
 
     x_log_ref[0] = x_l
     x_sign_ref[0] = x_s
-    carry_log_ref[...] = x_l[-1]
-    carry_sign_ref[...] = x_s[-1]
+    # static last-row slice: integer indexing lowers to a dynamic_slice,
+    # which Mosaic does not implement
+    carry_log_ref[...] = jax.lax.index_in_dim(x_l, bt - 1, keepdims=False)
+    carry_sign_ref[...] = jax.lax.index_in_dim(x_s, bt - 1, keepdims=False)
 
 
 def _prod_combine(e, l):
@@ -145,9 +147,7 @@ def _matrix_scan_kernel_zero_b(
     al = a_log_ref[0]  # (BT, d, d)
     asn = a_sign_ref[0]
 
-    a_star_l, a_star_s = jax.lax.associative_scan(
-        _prod_combine, (al, asn), axis=0
-    )
+    a_star_l, a_star_s = _tree_scan(_prod_combine, (al, asn))
 
     bt = al.shape[0]
     cl = jnp.broadcast_to(carry_log_ref[...], (bt,) + carry_log_ref.shape)
@@ -156,8 +156,10 @@ def _matrix_scan_kernel_zero_b(
 
     x_log_ref[0] = x_l
     x_sign_ref[0] = x_s
-    carry_log_ref[...] = x_l[-1]
-    carry_sign_ref[...] = x_s[-1]
+    # static last-row slice: integer indexing lowers to a dynamic_slice,
+    # which Mosaic does not implement
+    carry_log_ref[...] = jax.lax.index_in_dim(x_l, bt - 1, keepdims=False)
+    carry_sign_ref[...] = jax.lax.index_in_dim(x_s, bt - 1, keepdims=False)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))

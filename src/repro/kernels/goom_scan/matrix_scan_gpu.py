@@ -164,7 +164,7 @@ def matrix_scan_gpu_kernel_call(
         in_specs=[a_spec, a_spec, b_spec, b_spec, x0_spec, x0_spec],
         out_specs=[b_spec, b_spec],
         out_shape=out_shape,
-        compiler_params=plgpu.TritonCompilerParams(
+        compiler_params=plgpu.CompilerParams(
             num_warps=num_warps, num_stages=num_stages),
         interpret=interpret,
     )(a_log, a_sign, b_log, b_sign, x0_log, x0_sign)
@@ -208,7 +208,7 @@ def matrix_scan_gpu_kernel_call_zero_b(
         in_specs=[a_spec, a_spec, x0_spec, x0_spec],
         out_specs=[o_spec, o_spec],
         out_shape=out_shape,
-        compiler_params=plgpu.TritonCompilerParams(
+        compiler_params=plgpu.CompilerParams(
             num_warps=num_warps, num_stages=num_stages),
         interpret=interpret,
     )(a_log, a_sign, x0_log, x0_sign)
@@ -306,7 +306,7 @@ def matrix_scan_gpu_tree_call(
         in_specs=[a_spec, a_spec, b_spec, b_spec, x0_spec, x0_spec],
         out_specs=[b_spec, b_spec],
         out_shape=out_shape,
-        compiler_params=plgpu.TritonCompilerParams(
+        compiler_params=plgpu.CompilerParams(
             num_warps=num_warps, num_stages=num_stages),
         interpret=interpret,
     )(a_log, a_sign, b_log, b_sign, x0_log, x0_sign)
@@ -346,7 +346,7 @@ def matrix_scan_gpu_tree_call_zero_b(
         in_specs=[a_spec, a_spec, x0_spec, x0_spec],
         out_specs=[o_spec, o_spec],
         out_shape=out_shape,
-        compiler_params=plgpu.TritonCompilerParams(
+        compiler_params=plgpu.CompilerParams(
             num_warps=num_warps, num_stages=num_stages),
         interpret=interpret,
     )(a_log, a_sign, x0_log, x0_sign)
@@ -490,7 +490,7 @@ def matrix_scan_gpu_two_pass_call(
 
     a_spec = pl.BlockSpec((1, block_t, d, d), lambda gi, ti: (gi, ti, 0, 0))
     b_spec = pl.BlockSpec((1, block_t, d, m), lambda gi, ti: (gi, ti, 0, 0))
-    params = plgpu.TritonCompilerParams(
+    params = plgpu.CompilerParams(
         num_warps=num_warps, num_stages=num_stages)
 
     astar_l, astar_s, s0_l, s0_s = pl.pallas_call(
@@ -555,7 +555,7 @@ def matrix_scan_gpu_two_pass_call_zero_b(
 
     a_spec = pl.BlockSpec((1, block_t, d, d), lambda gi, ti: (gi, ti, 0, 0))
     o_spec = pl.BlockSpec((1, block_t, d, m), lambda gi, ti: (gi, ti, 0, 0))
-    params = plgpu.TritonCompilerParams(
+    params = plgpu.CompilerParams(
         num_warps=num_warps, num_stages=num_stages)
 
     astar_l, astar_s = pl.pallas_call(

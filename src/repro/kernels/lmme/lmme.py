@@ -76,7 +76,9 @@ def _lmme_kernel(
     # Exponentiate this K-tile near unit scale and contract on the MXU.
     ea = asn * jnp.exp(al - mr_new)  # (bn, bd)
     eb = bsn * jnp.exp(bl - mc_new)  # (bd, bm)
-    acc = acc + jnp.dot(ea, eb, preferred_element_type=jnp.float32)
+    # HIGHEST: an f32 contraction on the MXU, never a one-pass bf16 product
+    acc = acc + jnp.dot(ea, eb, precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32)
 
     acc_ref[...] = acc
     m_row_ref[...] = mr_new

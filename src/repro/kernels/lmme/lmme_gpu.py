@@ -14,7 +14,7 @@ Same math as the TPU kernel (``lmme.py``) reshaped for a GPU launch:
     tensor cores under the Triton lowering);
   * tile shapes are warp-friendly: powers of two, >= 16 on every ``pl.dot``
     dimension; ``num_warps`` / ``num_stages`` ride in via
-    ``plgpu.TritonCompilerParams``.
+    ``plgpu.CompilerParams``.
 
 Lowering: Pallas's Triton path on CUDA devices; ``interpret=True`` runs
 the identical body on CPU for CI parity (the ``pallas_gpu_interpret``
@@ -119,7 +119,7 @@ def lmme_gpu_kernel_call(
         in_specs=[a_spec, a_spec, b_spec, b_spec],
         out_specs=[o_spec, o_spec],
         out_shape=out_shape,
-        compiler_params=plgpu.TritonCompilerParams(
+        compiler_params=plgpu.CompilerParams(
             num_warps=num_warps, num_stages=num_stages),
         interpret=interpret,
     )(a_log, a_sign, b_log, b_sign)

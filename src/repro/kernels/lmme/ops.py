@@ -2,10 +2,9 @@
 
 Handles batching (arbitrary leading dims), padding to block multiples
 (padded contraction entries are exact zeros: log = -inf, so they contribute
-``exp(-inf) == 0`` to every sum — no masking needed), backend selection
-(``interpret=True`` off-TPU), and a custom VJP (backward pass reuses the
-reference implementation's autodiff on the saved inputs, which computes the
-same mathematical function).
+``exp(-inf) == 0`` to every sum — no masking needed), and a custom VJP
+(backward pass reuses the reference implementation's autodiff on the saved
+inputs, which computes the same mathematical function).
 """
 
 from __future__ import annotations
@@ -22,12 +21,6 @@ from .lmme import lmme_kernel_call
 from .lmme_gpu import lmme_gpu_kernel_call
 
 __all__ = ["lmme_pallas"]
-
-
-def _should_interpret() -> bool:
-    from repro.kernels.dispatch import current_platform  # cached, cheap
-
-    return current_platform() != "tpu"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, fill: float) -> jax.Array:
@@ -116,7 +109,7 @@ def lmme_pallas(
     block_d: int = 128,
     num_warps: int = 4,
     num_stages: int = 2,
-    interpret: bool | None = None,
+    interpret: bool = False,
     variant: str = "tpu",
 ) -> Goom:
     """LMME over GOOMs via the tiled online-rescaled Pallas kernels.
@@ -126,11 +119,14 @@ def lmme_pallas(
     the TPU-shaped kernel (sequential K grid + VMEM scratch) or the
     GPU-shaped one (in-kernel K loop + register carries, Triton lowering).
     """
-    if interpret is None:
-        interpret = _should_interpret()
-
     # Broadcast leading batch dims.
     batch = jnp.broadcast_shapes(a.shape[:-2], b.shape[:-2])
+    if 0 in batch:
+        # nothing to launch (associative scans combine empty slices), and a
+        # kernel grid cannot be empty
+        shape = batch + (a.shape[-2], b.shape[-1])
+        return Goom(jnp.full(shape, -jnp.inf, jnp.float32),
+                    jnp.ones(shape, jnp.float32))
     al = jnp.broadcast_to(a.log_abs, batch + a.shape[-2:]).astype(jnp.float32)
     asn = jnp.broadcast_to(a.sign, batch + a.shape[-2:]).astype(jnp.float32)
     bl = jnp.broadcast_to(b.log_abs, batch + b.shape[-2:]).astype(jnp.float32)

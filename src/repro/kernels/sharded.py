@@ -53,18 +53,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.goom import Goom, goom_zeros
 from repro.core.ops import goom_add, goom_mul, lmme_reference
 
-# jax >= 0.7 promotes shard_map to the top level (and renames check_rep to
-# check_vma) while dropping the experimental module; support both (same
-# shim style as tests/jax_compat).
-if hasattr(jax, "shard_map"):  # pragma: no cover - newer jax only
-    def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_rep)
-else:
-    from jax.experimental.shard_map import shard_map
 
 __all__ = [
     "ShardSpec",
+    "seq_sharded_lmme",
     "seq_sharded_diagonal_scan",
     "seq_sharded_matrix_scan",
     "seq_sharded_cumulative_lmme",
@@ -94,6 +86,32 @@ class ShardSpec:
         for a in self.batch_axes:
             n *= int(self.mesh.shape[a])
         return n
+
+
+def seq_sharded_lmme(a: Goom, b: Goom, *, spec: ShardSpec,
+                     local_lmme: Callable) -> Goom:
+    """LMME inside a shard_map over the whole mesh.
+
+    A Pallas TPU kernel is never partitioned automatically: in a program
+    over several devices it must run inside a shard_map.  The leading batch
+    dim (time, for the GOOM layer's ``B·u``) is split over the sequence axis
+    when it divides evenly, and the next (the batch) over the batch axes, as
+    the scans below place them; an operand without those dims, or a dim
+    that does not divide, is whole on every device."""
+    batch = jnp.broadcast_shapes(a.shape[:-2], b.shape[:-2])
+    seq = spec.seq_axis if batch and batch[0] % spec.n_shards == 0 else None
+    bp = _batch_entry(spec, batch[1] if len(batch) > 1 else None)
+    lead = (seq, bp)[:len(batch)]
+
+    def spec_for(x: Goom):  # batch dims align from the right, and broadcast
+        off = len(batch) - (x.ndim - 2)
+        return P(*(lead[g] if g < len(lead) and x.shape[g - off] == batch[g]
+                   else None for g in range(off, len(batch))))
+
+    return jax.shard_map(
+        local_lmme, mesh=spec.mesh, in_specs=(spec_for(a), spec_for(b)),
+        out_specs=P(*lead), check_vma=False,
+    )(a, b)
 
 
 # ---------------------------------------------------------------------------
@@ -211,10 +229,10 @@ def seq_sharded_matrix_scan(
         x_in = goom_add(lmme_reference(a_in, x0_l), b_in)
         return goom_add(lmme(astar, x_in), states0)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=spec.mesh,
         in_specs=(t_spec, t_spec, x_spec), out_specs=t_spec,
-        check_rep=False,
+        check_vma=False,
     )(a, b, x0g)
     return out[:t] if pad else out
 
@@ -256,9 +274,9 @@ def seq_sharded_cumulative_lmme(
         p_in = _g_index(pa_x, idx)
         return lmme(astar, p_in)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=spec.mesh, in_specs=(t_spec,), out_specs=t_spec,
-        check_rep=False,
+        check_vma=False,
     )(a)
     return out[:t] if pad else out
 
@@ -319,10 +337,10 @@ def seq_sharded_diagonal_scan(
         x_in_b = _g_bcast(x_in, astar.shape)
         return goom_add(goom_mul(astar, x_in_b), states0)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=spec.mesh,
         in_specs=(t_spec, t_spec, x_spec), out_specs=t_spec,
-        check_rep=False,
+        check_vma=False,
     )(a, b, x0g)
     return out[:t] if pad else out
 
@@ -368,7 +386,7 @@ def seq_sharded_associative_scan(fn, elems, *, spec: ShardSpec):
         return jax.tree.map(
             lambda l, s: jnp.where(idx == 0, l, s), local, stitched)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=spec.mesh, in_specs=(t_spec,), out_specs=t_spec,
-        check_rep=False,
+        check_vma=False,
     )(elems)

@@ -40,7 +40,7 @@ from repro.models.model import DecoderLM
 from repro.serve.steps import abstract_caches, make_decode_step, make_prefill_step
 from repro.sharding.rules import make_rules, use_rules
 from repro.train.optimizer import AdamW, cosine_schedule
-from repro.train.train_loop import TrainState, init_train_state, make_train_step
+from repro.train.train_loop import init_train_state, make_train_step, state_shardings
 
 
 # ---------------------------------------------------------------------------
@@ -54,14 +54,6 @@ def param_shardings(rules, params_abs, axes):
         is_leaf=lambda x: isinstance(x, tuple)
         and all(e is None or isinstance(e, str) for e in x),
     )
-
-
-def state_shardings(rules, state_abs: TrainState, p_shard):
-    rep = NamedSharding(rules.mesh, P())
-    opt_shard = {}
-    for k, v in state_abs.opt_state.items():
-        opt_shard[k] = p_shard if k in ("mu", "nu") else rep
-    return TrainState(params=p_shard, opt_state=opt_shard, step=rep, rng=rep)
 
 
 def batch_shardings(rules, batch_abs):

@@ -32,16 +32,18 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import compile_cache
 from repro.configs import SHAPES, get_config
 from repro.core import engine
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.common import unzip
 from repro.models.model import DecoderLM
-from repro.sharding.rules import make_rules, use_rules
+from repro.sharding.rules import make_rules, param_shardings, use_rules
 from repro.train.checkpoint import CheckpointManager
 from repro.train.data import DataConfig, SyntheticStream
 from repro.train.optimizer import AdamW, cosine_schedule
-from repro.train.train_loop import init_train_state, make_train_step
+from repro.train.train_loop import (init_train_state, make_train_step,
+                                    state_shardings)
 
 
 def main(argv=None):
@@ -77,13 +79,17 @@ def main(argv=None):
                     help="sequence-shard GOOM scans over the 'model' mesh "
                          "axis (maps the scan_seq logical axis there; the "
                          "host mesh is reshaped to (ndev/N, N)); 1 = off")
+    ap.add_argument("--devices", type=int, default=None,
+                    help="host mesh: use the first N devices (default: all)")
     ap.add_argument("--grad-compression", default=None, choices=[None, "int8"])
     ap.add_argument("--straggler-factor", type=float, default=2.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     if args.mesh == "host":
-        mesh = make_host_mesh(seq_shards=args.seq_shards)
+        mesh = make_host_mesh(seq_shards=args.seq_shards,
+                              n_devices=args.devices)
     else:
         mesh = make_production_mesh(multi_pod=args.mesh.endswith("multipod"))
         if args.seq_shards > 1 and mesh.shape["model"] != args.seq_shards:
@@ -113,15 +119,8 @@ def main(argv=None):
 
     # shardings
     params_abs, axes = model.init_shapes(key)
-    p_shard = jax.tree.map(
-        lambda sds, names: rules.sharding(sds.shape, list(names)),
-        params_abs, axes,
-        is_leaf=lambda x: isinstance(x, tuple)
-        and all(e is None or isinstance(e, str) for e in x),
-    )
+    p_shard = param_shardings(rules, params_abs, axes)
     state_abs = jax.eval_shape(lambda k: init_train_state(model, opt, k), key)
-    from repro.launch.dryrun import state_shardings  # same tree logic
-
     s_shard = state_shardings(rules, state_abs, p_shard)
     batch_sharding = rules.sharding((args.batch, args.seq_len), ["batch", None])
 

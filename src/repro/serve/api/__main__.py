@@ -15,6 +15,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 
+from ... import compile_cache
 from .gateway import Gateway
 from .server import ServeAPI, build_engine
 
@@ -33,6 +34,7 @@ def main(argv=None) -> None:
     ap.add_argument("--backend", default="auto")
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     eng, cfg = build_engine(
         args.arch, smoke=not args.full, max_slots=args.slots,
         page_len=args.page_len, chunk=args.chunk, backend=args.backend)

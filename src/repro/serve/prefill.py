@@ -25,16 +25,8 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import numpy as np
 
-from ..kernels.dispatch import current_platform
 from ..models.model import DecoderLM
 from .steps import _engine_scope
-
-
-def _donate(argnums):
-    # donation is a no-op (plus a warning) on CPU; only request it where
-    # XLA actually aliases buffers.  Platform comes from the cached
-    # single-read resolver, not a fresh jax.default_backend() call.
-    return argnums if current_platform() != "cpu" else ()
 
 
 class ChunkedPrefill:
@@ -73,8 +65,8 @@ class ChunkedPrefill:
             with _engine_scope(backend, mesh, seq_shards, blocks):
                 return model.decode_step(params, token, caches, index)
 
-        self._chunk_step = jax.jit(chunk_step, donate_argnums=_donate((2,)))
-        self._tail_step = jax.jit(tail_step, donate_argnums=_donate((2,)))
+        self._chunk_step = jax.jit(chunk_step, donate_argnums=(2,))
+        self._tail_step = jax.jit(tail_step, donate_argnums=(2,))
 
     def __call__(
         self, params, prompt, caches, *, start: int = 0,

@@ -65,7 +65,7 @@ import numpy as np
 
 from ..models.model import DecoderLM
 from . import state_cache
-from .prefill import ChunkedPrefill, _donate
+from .prefill import ChunkedPrefill
 from .steps import _engine_scope, make_decode_multi
 
 
@@ -302,8 +302,8 @@ class Engine:
                                  write_pages, table_row, term, eos_id,
                                  budget)
 
-        self._admit_chunk = jax.jit(admit_chunk, donate_argnums=_donate((1,)))
-        self._admit_tail = jax.jit(admit_tail, donate_argnums=_donate((1,)))
+        self._admit_chunk = jax.jit(admit_chunk, donate_argnums=(1,))
+        self._admit_tail = jax.jit(admit_tail, donate_argnums=(1,))
 
         self._caches = model.init_slot_caches(
             max_slots, page_len, page_size=self.page_size,
@@ -330,7 +330,7 @@ class Engine:
             lambda caches: state_cache.strip_checkpoint(meta, caches))
         self._gather = jax.jit(state_cache.gather_prefix)
         self._clear = jax.jit(state_cache.clear_slot_pages,
-                              donate_argnums=_donate((0,)))
+                              donate_argnums=(0,))
         self._queue: Deque[Request] = deque()
         self._active: Dict[int, _Active] = {}
         # next input token and its absolute position, per slot — both
@@ -710,7 +710,7 @@ class Engine:
         fn = self._decode_multi.get(k)
         if fn is None:
             fn = jax.jit(make_decode_multi(self.model, k, **self._scope),
-                         donate_argnums=_donate((2,)))
+                         donate_argnums=(2,))
             self._decode_multi[k] = fn
         return fn
 

@@ -19,6 +19,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.model import DecoderLM
 from .optimizer import clip_by_global_norm, global_norm
@@ -41,6 +42,15 @@ def init_train_state(model: DecoderLM, optimizer, key: jax.Array) -> TrainState:
         step=jnp.zeros((), jnp.int32),
         rng=key,
     )
+
+
+def state_shardings(rules, state_abs: TrainState, p_shard) -> TrainState:
+    """Shardings of a train state: optimizer moments follow the params,
+    everything else is replicated."""
+    rep = NamedSharding(rules.mesh, P())
+    opt_shard = {k: p_shard if k in ("mu", "nu") else rep
+                 for k in state_abs.opt_state}
+    return TrainState(params=p_shard, opt_state=opt_shard, step=rep, rng=rep)
 
 
 def make_train_step(

@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from hyp_compat import given, settings, st  # degrades gracefully w/o hypothesis
-from jax_compat import abstract_mesh
 
 from repro.core.goom import Goom, from_goom, to_goom
 from repro.core.ops import goom_add, goom_mul, goom_neg, lmme_naive
@@ -79,7 +78,7 @@ _DIMS = st.lists(st.sampled_from([1, 2, 3, 4, 6, 8, 16, 28, 64]),
 def test_spec_never_reuses_mesh_axis_and_divides(names, dims):
     n = min(len(names), len(dims))
     names, dims = names[:n], dims[:n]
-    mesh = abstract_mesh((4, 2), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((4, 2), ("data", "model"))
     rules = make_rules(mesh)
     spec = rules.spec(dims, names)
     sizes = {"data": 4, "model": 2}

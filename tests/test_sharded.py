@@ -106,6 +106,26 @@ def test_use_mesh_defaults_to_seq_axis_name():
         assert engine.get_config().seq_axis == "model"
 
 
+@pytest.mark.parametrize("b_shape", [(4, 2, 3, 4, 1), (4, 1)])
+def test_seq_sharded_lmme_plumbing(b_shape):
+    """The shard_map around a kernel LMME: a leading batch dim splits over
+    the sequence axis; without one every device takes the whole product."""
+    from jax.sharding import Mesh
+
+    from repro.core.ops import lmme_reference
+    from repro.kernels.sharded import ShardSpec, seq_sharded_lmme
+
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "seq"))
+    k1, k2 = jax.random.split(KEY)
+    a = to_goom(jax.random.normal(k1, (3, 4, 4) if len(b_shape) > 2 else (4, 4)))
+    b = to_goom(jax.random.normal(k2, b_shape))
+    got = seq_sharded_lmme(a, b, spec=ShardSpec(mesh, "seq"),
+                           local_lmme=lmme_reference)
+    want = lmme_reference(a, b)
+    np.testing.assert_array_equal(got.log_abs, want.log_abs)
+    np.testing.assert_array_equal(got.sign, want.sign)
+
+
 # ---------------------------------------------------------------------------
 # multi-device parity (the acceptance bars)
 # ---------------------------------------------------------------------------
@@ -284,3 +304,52 @@ def test_sharded_local_pallas_interpret_matches_reference():
         with engine.use_mesh(mesh18()):
             got = engine.matrix_scan(a, b, None)
     assert_log_close(got, want, rtol=1e-4)
+
+
+@needs8
+@pytest.mark.parametrize("s", [16, 3])
+def test_lmme_under_mesh_matches_local(s):
+    """engine.lmme with sequence shards active (the GOOM layer's B·u):
+    split over the sequence axis when S divides, replicated otherwise."""
+    k1, k2 = jax.random.split(KEY)
+    a = to_goom(jax.random.normal(k1, (3, 4, 4)))
+    b = to_goom(jax.random.normal(k2, (s, 2, 3, 4, 1)))
+    want, got = ref_and_sharded(engine.lmme, a, b)
+    assert_log_close(got, want)
+
+
+@needs8
+@pytest.mark.parametrize("a_shape,b_shape,spec", [
+    ((3, 4, 4), (16, 2, 3, 4, 1), ("seq", "data")),     # B·u: S over seq, B over data
+    ((2, 3, 4, 4), (16, 2, 3, 4, 1), ("seq", "data")),  # A carries the batch dim
+    ((16, 1, 3, 4, 4), (16, 2, 3, 4, 1), ("seq", "data")),  # A broadcasts over B
+    ((3, 4, 4), (6, 3, 3, 4, 1), (None, None)),         # neither divides
+])
+def test_lmme_under_data_seq_mesh(a_shape, b_shape, spec):
+    """On a data x seq mesh the B·u LMME splits time over 'seq' and the
+    batch over 'data': no device computes another replica's batch."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("data", "seq"))
+    k1, k2 = jax.random.split(KEY)
+    a = to_goom(jax.random.normal(k1, a_shape))
+    b = to_goom(jax.random.normal(k2, b_shape))
+    with engine.use_backend("xla_reference"):
+        want = engine.lmme(a, b)
+        with engine.use_mesh(mesh, seq_axis="seq", batch_axis="data"):
+            got = jax.jit(engine.lmme)(a, b)
+    assert_log_close(got, want)
+    sharding = got.log_abs.sharding
+    assert sharding.is_equivalent_to(
+        jax.sharding.NamedSharding(mesh, P(*spec)), got.ndim)
+
+
+def test_host_mesh_takes_the_first_n_devices():
+    from repro.launch.mesh import make_host_mesh
+
+    mesh = make_host_mesh(n_devices=1)
+    assert dict(mesh.shape) == {"data": 1, "model": 1}
+    assert mesh.devices.flat[0] == jax.devices()[0]
+    if NDEV >= 8:
+        mesh = make_host_mesh(seq_shards=4, n_devices=8)
+        assert dict(mesh.shape) == {"data": 2, "model": 4}

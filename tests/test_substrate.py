@@ -7,7 +7,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax_compat import abstract_mesh
 
 from repro.sharding.rules import make_rules
 from repro.train.checkpoint import CheckpointManager
@@ -146,7 +145,7 @@ def test_checkpoint_restore_latest_resharding(tmp_path):
 # ---------------------------------------------------------------------------
 def _mesh22():
     # AbstractMesh: axis sizes without needing real devices (1-CPU CI)
-    return abstract_mesh((2, 2), ("data", "model"))
+    return jax.sharding.AbstractMesh((2, 2), ("data", "model"))
 
 
 def test_rules_divisibility_drop():
@@ -167,7 +166,7 @@ def test_rules_no_axis_reuse():
 
 
 def test_rules_multi_axis_batch():
-    mesh = abstract_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.sharding.AbstractMesh((2, 2, 2), ("pod", "data", "model"))
     rules = make_rules(mesh)
     spec = rules.spec((8, 128), ["batch", None])
     assert spec[0] == ("pod", "data")

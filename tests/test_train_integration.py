@@ -66,6 +66,25 @@ def test_resume_is_bit_exact(tmp_path):
         np.testing.assert_array_equal(np.asarray(pa), np.asarray(pb))
 
 
+def test_train_launcher_runs_and_resumes(tmp_path, capsys, monkeypatch):
+    """The training entry point end to end on the host mesh: steps,
+    checkpoint, resume from it."""
+    from repro.launch.train import main
+
+    # the launcher then leaves this process's compile cache as it is
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax"))
+    argv = ["--arch", "goom-rnn-124m", "--smoke", "--task", "copy",
+            "--seq-len", "32", "--batch", "4", "--log-every", "1",
+            "--ckpt-dir", str(tmp_path)]
+    main(argv + ["--steps", "2"])
+    main(argv + ["--steps", "3"])
+    out = capsys.readouterr().out
+    assert "resumed from checkpoint step 2" in out
+    losses = [float(line.split()[3]) for line in out.splitlines()
+              if line.startswith("step ")]
+    assert len(losses) == 3 and np.isfinite(losses).all()
+
+
 def test_microbatched_grads_match_full_batch():
     """Accumulated microbatch gradients equal the full-batch gradient
     (per-microbatch token counts are equal here, so means compose).

@@ -629,7 +629,7 @@ def serve_api(preset: str = "full", backend: str = "auto"):
         "rejected": snap["requests"]["rejected"],
         "ttft_ms": lat["ttft"],
         "request_ms": lat["request"],
-        "decode_step_ms": lat["decode_step"],
+        "engine_step_ms": lat["engine_step"],
     }
     assert all(n == gen for n, _ in out)  # every client got its budget
     print("metric,value")
@@ -639,8 +639,8 @@ def serve_api(preset: str = "full", backend: str = "auto"):
     print(f"ttft_ms,p50 {lat['ttft']['p50']:.0f} / p99 {lat['ttft']['p99']:.0f}")
     print(f"request_ms,p50 {lat['request']['p50']:.0f} / "
           f"p99 {lat['request']['p99']:.0f}")
-    print(f"decode_step_ms,p50 {lat['decode_step']['p50']:.1f} / "
-          f"p99 {lat['decode_step']['p99']:.1f}")
+    print(f"engine_step_ms,p50 {lat['engine_step']['p50']:.1f} / "
+          f"p99 {lat['engine_step']['p99']:.1f}")
     return res
 
 

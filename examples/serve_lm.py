@@ -99,7 +99,7 @@ def main():
         snap = api.get_status(srv.host, srv.port)
         lat = snap["latency_ms"]
         print(f"/status: {snap['requests']['finished']} finished, "
-              f"decode step p50 {lat['decode_step']['p50']:.1f} ms, "
+              f"engine step p50 {lat['engine_step']['p50']:.1f} ms, "
               f"ttft p50 {lat['ttft']['p50']:.0f} ms, "
               f"request p50 {lat['request']['p50']:.0f} ms")
     finally:

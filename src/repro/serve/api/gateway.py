@@ -27,7 +27,7 @@ import time
 import traceback
 from typing import Any, AsyncIterator, Dict, List, Optional, Tuple
 
-from ..metrics import ServeMetrics
+from ..metrics import ServeMetrics, span
 from ..scheduler import Engine, Request
 
 
@@ -114,10 +114,8 @@ class Gateway:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         engine.stream_callback = self._on_stream
-        # seed the prefix-cache and decode gauges so /status has them
-        # before the first step (and when prefix reuse is disabled)
-        self.metrics.record_prefix_stats(engine.prefix_stats())
-        self.metrics.record_decode_stats(engine.decode_stats())
+        # the engine logs admission start and first token per request
+        engine.metrics = self.metrics
 
     # -- lifecycle -----------------------------------------------------------
     def start(self) -> "Gateway":
@@ -161,6 +159,7 @@ class Gateway:
         state = _StreamState(q, asyncio.get_running_loop(), time.monotonic())
         with self._lock:
             self._streams[uid] = state
+        self.metrics.stamp(uid, "submitted", state.submitted_at)
         self.metrics.record_submitted()
         self._pending_submits += 1
         self._cmds.put(("submit", req))
@@ -174,7 +173,8 @@ class Gateway:
     def _run(self) -> None:
         eng = self.engine
         while not self._stop.is_set():
-            self._drain_cmds(block=not eng.has_work)
+            with span("serve.drain"):
+                self._drain_cmds(block=not eng.has_work)
             if self._stop.is_set():
                 return
             if not eng.has_work:
@@ -185,13 +185,11 @@ class Gateway:
                 # drained above, so a submit that arrives now waits at
                 # most one horizon before the engine sees its queue
                 # non-empty and drops back to k=1 dispatches
-                t0 = time.perf_counter()
-                eng.step()
-                self.metrics.record_step(time.perf_counter() - t0,
-                                         eng.n_active)
-                # engine-thread-only counters, synced as gauges for /status
-                self.metrics.record_prefix_stats(eng.prefix_stats())
-                self.metrics.record_decode_stats(eng.decode_stats())
+                with span("serve.step"):
+                    t0 = time.perf_counter()
+                    eng.step()
+                    self.metrics.record_step(time.perf_counter() - t0,
+                                             eng.n_active)
             except Exception:
                 traceback.print_exc()
                 self._fail_all("error")

@@ -8,8 +8,9 @@ carries no web-framework dependency.  Endpoints:
   ``max_tokens``, ``stream`` (SSE token-by-token when true), ``eos_id``,
   ``deadline_ms``.  Backpressure: 429 + ``Retry-After`` once the
   gateway's waiting queue passes its watermark.
-* ``GET /status`` — engine gauges (slot occupancy, queue depth) +
-  ``ServeMetrics`` counters/latency percentiles as JSON.
+* ``GET /status`` — engine gauges (slot occupancy, queue depth) and
+  counters (prefix cache, decode, prefill) + ``ServeMetrics``
+  counters/latency percentiles as JSON.
 * ``GET /healthz`` — liveness.
 
 Every connection is ``Connection: close`` (one exchange per socket):
@@ -25,8 +26,10 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
+import time
 from typing import Optional, Tuple
 
+from ..metrics import span
 from . import sse
 from .gateway import Gateway, QueueFull, StreamHandle
 
@@ -114,30 +117,17 @@ class ServeAPI:
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
         try:
-            try:
-                parsed = await _read_request(reader)
-            except ValueError as e:
-                writer.write(_error(413, str(e)))
-                return
-            except (asyncio.IncompleteReadError, ConnectionError):
-                return
-            if parsed is None:
-                return
-            method, path, headers, body = parsed
-            if path == "/v1/completions":
-                if method != "POST":
-                    writer.write(_error(405, "use POST"))
-                    return
-                await self._completions(body, reader, writer)
-            elif path == "/status":
-                if method != "GET":
-                    writer.write(_error(405, "use GET"))
-                    return
-                writer.write(_response(200, self.status()))
-            elif path == "/healthz":
-                writer.write(_response(200, {"ok": True}))
-            else:
-                writer.write(_error(404, f"no route {path}"))
+            received = time.monotonic()
+            with span("serve.http"):
+                # request read -> a completion handed to the gateway
+                accepted = await self._route(reader, writer)
+            if accepted is not None:
+                handle, stream, n_prompt = accepted
+                self.gateway.metrics.stamp(handle.uid, "received", received)
+                if stream:
+                    await self._stream_sse(handle, reader, writer)
+                else:
+                    await self._respond_whole(handle, n_prompt, writer)
         except (ConnectionError, asyncio.CancelledError):
             pass
         finally:
@@ -151,14 +141,43 @@ class ServeAPI:
             except (ConnectionError, asyncio.CancelledError):
                 pass
 
+    async def _route(self, reader, writer
+                     ) -> Optional[Tuple[StreamHandle, bool, int]]:
+        """Read one request and answer it, except an accepted completion:
+        that comes back as ``(handle, stream, prompt_tokens)``."""
+        try:
+            parsed = await _read_request(reader)
+        except ValueError as e:
+            writer.write(_error(413, str(e)))
+            return None
+        except asyncio.IncompleteReadError:
+            return None
+        if parsed is None:
+            return None
+        method, path, headers, body = parsed
+        if path == "/v1/completions":
+            if method != "POST":
+                writer.write(_error(405, "use POST"))
+                return None
+            return await self._submit(body, writer)
+        if path == "/status":
+            if method != "GET":
+                writer.write(_error(405, "use GET"))
+                return None
+            writer.write(_response(200, self.status()))
+        elif path == "/healthz":
+            writer.write(_response(200, {"ok": True}))
+        else:
+            writer.write(_error(404, f"no route {path}"))
+        return None
+
     def status(self) -> dict:
         eng = self.gateway.engine
         snap = self.gateway.metrics.snapshot()
-        # live read, like the engine gauges below: the metrics copy is
-        # synced after each step, which can lag the terminal stream event
-        # a fast client reacts to (pure-python counters; GIL-safe)
+        # the engine's own counters, read live (pure-python; GIL-safe)
         snap["prefix_cache"] = eng.prefix_stats()
         snap["decode"] = eng.decode_stats()
+        snap["prefill"] = eng.prefill_stats()
         snap["engine"] = {
             "max_slots": eng.max_slots,
             "n_active": eng.n_active,
@@ -173,7 +192,8 @@ class ServeAPI:
         return snap
 
     # -- /v1/completions -----------------------------------------------------
-    async def _completions(self, body: bytes, reader, writer) -> None:
+    async def _submit(self, body: bytes, writer
+                      ) -> Optional[Tuple[StreamHandle, bool, int]]:
         try:
             req = json.loads(body.decode("utf-8"))
             prompt = [int(t) for t in req["prompt"]]
@@ -187,7 +207,7 @@ class ServeAPI:
         except (KeyError, TypeError, ValueError,
                 json.JSONDecodeError) as e:
             writer.write(_error(400, f"malformed request: {e}"))
-            return
+            return None
         try:
             handle = await self.gateway.submit(
                 prompt=prompt, max_new_tokens=max_tokens, eos_id=eos_id,
@@ -196,27 +216,28 @@ class ServeAPI:
             writer.write(_error(
                 429, str(e),
                 extra_headers=[("Retry-After", str(e.retry_after))]))
-            return
+            return None
         except ValueError as e:
             writer.write(_error(400, str(e)))
-            return
-        if stream:
-            await self._stream_sse(handle, reader, writer)
-        else:
-            toks, reason = await handle.collect()
-            writer.write(_response(200, {
-                "id": handle.uid,
-                "object": "text_completion",
-                "choices": [{
-                    "index": 0,
-                    "tokens": toks,
-                    "text": " ".join(str(t) for t in toks),
-                    "finish_reason": reason,
-                }],
-                "usage": {"prompt_tokens": len(prompt),
-                          "completion_tokens": len(toks),
-                          "total_tokens": len(prompt) + len(toks)},
-            }))
+            return None
+        return handle, stream, len(prompt)
+
+    async def _respond_whole(self, handle: StreamHandle, n_prompt: int,
+                             writer) -> None:
+        toks, reason = await handle.collect()
+        writer.write(_response(200, {
+            "id": handle.uid,
+            "object": "text_completion",
+            "choices": [{
+                "index": 0,
+                "tokens": toks,
+                "text": " ".join(str(t) for t in toks),
+                "finish_reason": reason,
+            }],
+            "usage": {"prompt_tokens": n_prompt,
+                      "completion_tokens": len(toks),
+                      "total_tokens": n_prompt + len(toks)},
+        }))
 
     async def _stream_sse(self, handle: StreamHandle, reader,
                           writer) -> None:
@@ -237,19 +258,22 @@ class ServeAPI:
                     batch.cancel()
                     return
                 toks, reason = batch.result()
-                for i, tok in enumerate(toks):
-                    fin = reason if i == len(toks) - 1 else None
-                    writer.write(sse.encode_event(sse.completion_chunk(
-                        handle.uid, tok, idx, fin)))
-                    idx += 1
-                if reason is not None and not toks:
-                    writer.write(sse.encode_event(sse.completion_chunk(
-                        handle.uid, None, idx, reason)))
-                try:
-                    await writer.drain()
-                except ConnectionError:
-                    handle.cancel()
+                if toks and not idx:
+                    # the first token's frame: taken -> drained
+                    taken = time.monotonic()
+                    with span("serve.sse_first", uid=handle.uid):
+                        sent = await self._write_batch(handle, toks, reason,
+                                                       idx, writer)
+                    if sent:
+                        metrics = self.gateway.metrics
+                        metrics.stamp(handle.uid, "first_taken", taken)
+                        metrics.stamp(handle.uid, "first_frame")
+                else:
+                    sent = await self._write_batch(handle, toks, reason, idx,
+                                                   writer)
+                if not sent:
                     return
+                idx += len(toks)
                 if reason is not None:
                     writer.write(sse.DONE_EVENT)
                     return
@@ -258,6 +282,25 @@ class ServeAPI:
             watchdog.cancel()
             if not batch.done():
                 batch.cancel()
+
+    @staticmethod
+    async def _write_batch(handle: StreamHandle, toks, reason, idx: int,
+                           writer) -> bool:
+        """One batch of tokens as SSE frames, drained; False when the
+        client is gone (the request is then cancelled)."""
+        for i, tok in enumerate(toks):
+            fin = reason if i == len(toks) - 1 else None
+            writer.write(sse.encode_event(sse.completion_chunk(
+                handle.uid, tok, idx + i, fin)))
+        if reason is not None and not toks:
+            writer.write(sse.encode_event(sse.completion_chunk(
+                handle.uid, None, idx, reason)))
+        try:
+            await writer.drain()
+        except ConnectionError:
+            handle.cancel()
+            return False
+        return True
 
 
 class BackgroundServer:

@@ -26,6 +26,7 @@ import jax
 import numpy as np
 
 from ..models.model import DecoderLM
+from .metrics import span
 from .steps import _engine_scope
 
 
@@ -51,22 +52,25 @@ class ChunkedPrefill:
             raise ValueError(f"chunk must be >= 1, got {chunk}")
         self.model = model
         self.chunk = chunk
-        # dispatch counters: prefix-reuse tests assert a warm hit issues
-        # exactly the suffix's chunks, by deltas of these
+        # dispatch counters (``Engine.prefill_stats()``): prefix-reuse
+        # tests assert a warm hit issues exactly the suffix's chunks, by
+        # deltas of these
         self.n_chunk_calls = 0
         self.n_tail_calls = 0
 
-        def chunk_step(params, tokens, caches, positions):
+        # the functions' names are the compiled programs' names in a
+        # device trace (``jit_prefill_chunk``, ``jit_prefill_tail``)
+        def prefill_chunk(params, tokens, caches, positions):
             with _engine_scope(backend, mesh, seq_shards, blocks):
                 return model.prefill(params, tokens, caches,
                                      positions=positions)
 
-        def tail_step(params, token, caches, index):
+        def prefill_tail(params, token, caches, index):
             with _engine_scope(backend, mesh, seq_shards, blocks):
                 return model.decode_step(params, token, caches, index)
 
-        self._chunk_step = jax.jit(chunk_step, donate_argnums=(2,))
-        self._tail_step = jax.jit(tail_step, donate_argnums=(2,))
+        self._chunk_step = jax.jit(prefill_chunk, donate_argnums=(2,))
+        self._tail_step = jax.jit(prefill_tail, donate_argnums=(2,))
 
     def __call__(
         self, params, prompt, caches, *, start: int = 0,
@@ -103,16 +107,19 @@ class ChunkedPrefill:
         for j in range(n_full):
             toks = prompt[None, j * c:(j + 1) * c]
             positions = np.arange(pos, pos + c, dtype=np.int32)[None]
-            logits, caches = self._chunk_step(params, toks, caches, positions)
+            with span("serve.prefill.chunk"):
+                logits, caches = self._chunk_step(params, toks, caches,
+                                                  positions)
             self.n_chunk_calls += 1
             pos += c
             if (capture is not None and capture_every
                     and pos % capture_every == 0):
                 capture(pos, caches)
         for t in range(n_full * c, p):
-            logits, caches = self._tail_step(
-                params, prompt[None, t:t + 1],
-                caches, np.asarray([pos], np.int32))
+            with span("serve.prefill.tail"):
+                logits, caches = self._tail_step(
+                    params, prompt[None, t:t + 1],
+                    caches, np.asarray([pos], np.int32))
             self.n_tail_calls += 1
             pos += 1
         return logits[:, -1, :], caches, pos

@@ -65,6 +65,7 @@ import numpy as np
 
 from ..models.model import DecoderLM
 from . import state_cache
+from .metrics import ServeMetrics, span
 from .prefill import ChunkedPrefill
 from .steps import _engine_scope, make_decode_multi
 
@@ -245,6 +246,11 @@ class Engine:
         # once, on the terminal event.  Settable after construction (the
         # api gateway attaches itself here).
         self.stream_callback = stream_callback
+        # optional per-request log (``ServeMetrics.stamp``): admission
+        # start and first token, two clock reads per admission.  Set
+        # after construction, like stream_callback (the gateway attaches
+        # its own); None reads no clock.
+        self.metrics: Optional[ServeMetrics] = None
 
         self._prefill = ChunkedPrefill(
             model, chunk, backend=backend, mesh=mesh, seq_shards=seq_shards,
@@ -280,6 +286,8 @@ class Engine:
             return (first, slot_caches, tok_vec.at[slot].set(first),
                     pos_vec.at[slot].set(next_pos), term)
 
+        # the function names below name the compiled programs in a device
+        # trace (``jit_admit_chunk``, ``jit_fresh_cache``, ...)
         def admit_chunk(params, slot_caches, caches, tokens, positions,
                         slot, tok_vec, pos_vec, write_pages, table_row,
                         term, eos_id, budget):
@@ -310,7 +318,10 @@ class Engine:
             cache_pages=int(cache_pages))
         # fresh per-request prefill cache as one compiled executable (the
         # eager zeros tree costs a dispatch per leaf otherwise)
-        self._fresh = jax.jit(lambda: model.init_caches(1, self._kv_len))
+        def fresh_cache():
+            return model.init_caches(1, self._kv_len)
+
+        self._fresh = jax.jit(fresh_cache)
         self._alloc = state_cache.SlotAllocator(max_slots)
         # host-side page bookkeeping: the pool refcounts every page, the
         # radix index maps cached prompt block-prefixes to (page, carry
@@ -326,11 +337,16 @@ class Engine:
             lambda: model.init_slot_caches(
                 max_slots, page_len, page_size=self.page_size,
                 cache_pages=int(cache_pages))))
-        self._snapshot = jax.jit(
-            lambda caches: state_cache.strip_checkpoint(meta, caches))
+
+        def snapshot_carry(caches):
+            return state_cache.strip_checkpoint(meta, caches)
+
+        def clear_slot(slot_caches, slot):
+            return state_cache.clear_slot_pages(slot_caches, slot)
+
+        self._snapshot = jax.jit(snapshot_carry)
         self._gather = jax.jit(state_cache.gather_prefix)
-        self._clear = jax.jit(state_cache.clear_slot_pages,
-                              donate_argnums=(0,))
+        self._clear = jax.jit(clear_slot, donate_argnums=(0,))
         self._queue: Deque[Request] = deque()
         self._active: Dict[int, _Active] = {}
         # next input token and its absolute position, per slot — both
@@ -365,6 +381,10 @@ class Engine:
         self.n_dispatches = 0
         self.n_decode_steps = 0
         self._last_horizon = 0
+        # admission counters (see prefill_stats)
+        self.n_admissions = 0
+        self.n_prompt_tokens = 0
+        self.n_finisher_calls = 0
 
     # -- bookkeeping --------------------------------------------------------
     @property
@@ -422,6 +442,24 @@ class Engine:
             "syncs_per_token": syncs / max(s, 1),
             "horizon_max": self.eos_scan_every,
             "last_horizon": self._last_horizon,
+        }
+
+    def prefill_stats(self) -> Dict[str, Any]:
+        """Admission counters (host-side, cheap).
+
+        ``admissions`` counts requests admitted into a slot,
+        ``prompt_tokens`` the prompt tokens their prefill computed (a
+        prefix-cache hit's tokens are not computed: ``prefix_stats()``
+        counts them), and ``chunk_calls`` / ``tail_calls`` /
+        ``finisher_calls`` the admission dispatches that did it: full
+        chunks, single-token steps and one fused finisher per admission.
+        ``GET /status`` reads this live."""
+        return {
+            "admissions": self.n_admissions,
+            "prompt_tokens": self.n_prompt_tokens,
+            "chunk_calls": self._prefill.n_chunk_calls,
+            "tail_calls": self._prefill.n_tail_calls,
+            "finisher_calls": self.n_finisher_calls,
         }
 
     def result(self, uid) -> List[int]:
@@ -601,62 +639,76 @@ class Engine:
                 self._emit(req, [], "timeout")
                 finished.append(req.uid)
                 continue
-            prompt = np.asarray(req.prompt, np.int32).reshape(-1)
-            p = int(prompt.shape[0])
-            c = self._prefill.chunk
-            r = p % c
-            ps, mb = self.page_size, self._max_blocks
-            sent = self._pool.sentinel
-            slot = self._alloc.allocate()
-            # the fused step reprocesses the prompt's final piece (a full
-            # chunk when the length divides, the last token otherwise) —
-            # a prefix hit must stop short of it so its logits are real
-            fused_start = p - (1 if r else c)
-            hit_blocks, hit_pages, ckpt = 0, [], None
-            if self.prefix_reuse:
-                hit_blocks, hit_pages, ckpt = self._index.match(
-                    prompt.tolist(), fused_start // ps)
-                # resume only on chunk-aligned boundaries: the suffix then
-                # replays the from-scratch chunk schedule bit-for-bit
-                # (always aligned when page_size % chunk == 0)
-                while hit_blocks and (hit_blocks * ps) % c:
-                    hit_blocks -= 1
-                hit_pages = hit_pages[:hit_blocks]
-            # the slot takes its page refs up front, before eviction can
-            # run: reserve() below may drop the very index nodes we hit
-            for pg in hit_pages:
-                self._pool.ref(pg)
-            self._index.reserve(mb - hit_blocks)
-            fresh = self._pool.alloc(mb - hit_blocks)
-            if fresh is None:  # sizing invariant guarantees this never trips
-                raise RuntimeError("page pool exhausted at admission")
-            table_row = hit_pages + fresh             # the slot's page table
-            write_row = [sent] * hit_blocks + fresh   # skip shared pages
-            hit_len = hit_blocks * ps
-            if hit_len:
-                # densify the cached prefix: pool pages through the hit
-                # blocks (zeros past them) + the carry checkpoint at hit_len
-                gather_row = np.asarray(
-                    hit_pages + [sent] * (mb - hit_blocks), np.int32)
-                caches = self._gather(self._caches, ckpt, gather_row)
-                self._tokens_saved += hit_len
-            else:
-                caches = self._fresh()
-            head = prompt[hit_len:fused_start]
-            captures: Dict[int, Any] = {}
-            if head.size:
-                _, caches, _ = self._prefill(
-                    self.params, head, caches, start=hit_len,
-                    capture_every=ps,
-                    capture=lambda pos, tree: captures.__setitem__(
-                        pos, self._snapshot(tree)))
-            slot = jnp.asarray(slot, jnp.int32)
-            wp = np.asarray(write_row, np.int32)
-            tr = np.asarray(table_row, np.int32)
-            # termination row: -1 = "no EOS" (no token id is negative);
-            # the budget counts decode steps after the admission token
-            eos = np.int32(-1 if req.eos_id is None else req.eos_id)
-            budget = np.int32(req.max_new_tokens - 1)
+            with span("serve.admit", uid=req.uid):
+                self._stamp(req.uid, "admit_start")
+                if self._admit_one(req, deadline):
+                    finished.append(req.uid)
+        return finished
+
+    def _stamp(self, uid, event: str) -> None:
+        if self.metrics is not None:
+            self.metrics.stamp(uid, event)
+
+    def _admit_one(self, req: Request, deadline: Optional[float]) -> bool:
+        """Prefill ``req`` into a free slot and arm the slot; True when
+        the request finished at admission (EOS or a one-token budget)."""
+        prompt = np.asarray(req.prompt, np.int32).reshape(-1)
+        p = int(prompt.shape[0])
+        c = self._prefill.chunk
+        r = p % c
+        ps, mb = self.page_size, self._max_blocks
+        sent = self._pool.sentinel
+        slot = self._alloc.allocate()
+        # the fused step reprocesses the prompt's final piece (a full
+        # chunk when the length divides, the last token otherwise) —
+        # a prefix hit must stop short of it so its logits are real
+        fused_start = p - (1 if r else c)
+        hit_blocks, hit_pages, ckpt = 0, [], None
+        if self.prefix_reuse:
+            hit_blocks, hit_pages, ckpt = self._index.match(
+                prompt.tolist(), fused_start // ps)
+            # resume only on chunk-aligned boundaries: the suffix then
+            # replays the from-scratch chunk schedule bit-for-bit
+            # (always aligned when page_size % chunk == 0)
+            while hit_blocks and (hit_blocks * ps) % c:
+                hit_blocks -= 1
+            hit_pages = hit_pages[:hit_blocks]
+        # the slot takes its page refs up front, before eviction can
+        # run: reserve() below may drop the very index nodes we hit
+        for pg in hit_pages:
+            self._pool.ref(pg)
+        self._index.reserve(mb - hit_blocks)
+        fresh = self._pool.alloc(mb - hit_blocks)
+        if fresh is None:  # sizing invariant guarantees this never trips
+            raise RuntimeError("page pool exhausted at admission")
+        table_row = hit_pages + fresh             # the slot's page table
+        write_row = [sent] * hit_blocks + fresh   # skip shared pages
+        hit_len = hit_blocks * ps
+        if hit_len:
+            # densify the cached prefix: pool pages through the hit
+            # blocks (zeros past them) + the carry checkpoint at hit_len
+            gather_row = np.asarray(
+                hit_pages + [sent] * (mb - hit_blocks), np.int32)
+            caches = self._gather(self._caches, ckpt, gather_row)
+            self._tokens_saved += hit_len
+        else:
+            caches = self._fresh()
+        head = prompt[hit_len:fused_start]
+        captures: Dict[int, Any] = {}
+        if head.size:
+            _, caches, _ = self._prefill(
+                self.params, head, caches, start=hit_len,
+                capture_every=ps,
+                capture=lambda pos, tree: captures.__setitem__(
+                    pos, self._snapshot(tree)))
+        slot = jnp.asarray(slot, jnp.int32)
+        wp = np.asarray(write_row, np.int32)
+        tr = np.asarray(table_row, np.int32)
+        # termination row: -1 = "no EOS" (no token id is negative);
+        # the budget counts decode steps after the admission token
+        eos = np.int32(-1 if req.eos_id is None else req.eos_id)
+        budget = np.int32(req.max_new_tokens - 1)
+        with span("serve.admit.finish"):
             if r:
                 (first, self._caches, self._tokens, self._pos,
                  self._term) = self._admit_tail(
@@ -672,36 +724,44 @@ class Engine:
                     np.arange(p - c, p, dtype=np.int32)[None],
                     slot, self._tokens, self._pos, wp, tr,
                     self._term, eos, budget)
-            self._slot_pages[int(slot)] = list(table_row)
-            if self.prefix_reuse:
-                # publish only blocks fully covered by full-chunk calls
-                # (captured checkpoints): future hits on them replay the
-                # same compiled schedule regardless of this prompt's tail
-                pub_blocks = (hit_len + (head.size // c) * c) // ps
-                ckpts = [None] * hit_blocks + [
-                    captures.get((b + 1) * ps)
-                    for b in range(hit_blocks, pub_blocks)]
-                self._index.publish(prompt.tolist(),
-                                    table_row[:pub_blocks], ckpts)
-            act = _Active(request=req, slot=int(slot), first=first, out=[],
-                          start_step=self._step_id, deadline=deadline)
-            self._active[int(slot)] = act
-            if req.max_new_tokens == 1 or req.eos_id is not None or req.stream:
-                # needs the value now: the request may finish before any
-                # decode step, and a streaming client gets its first token
-                # at admission (TTFT does not wait for a decode horizon)
-                act.out.append(self._flight.scalar(first))
-                reason = None
-                if req.eos_id is not None and act.out[0] == req.eos_id:
-                    reason = "stop"
-                elif req.max_new_tokens == 1:
-                    reason = "length"
-                act.n_streamed = len(act.out)
-                if req.stream or reason is not None:
-                    self._emit(req, list(act.out), reason)
-                if reason is not None:
-                    finished.append(self._finish(act, reason))
-        return finished
+        self._slot_pages[int(slot)] = list(table_row)
+        if self.prefix_reuse:
+            # publish only blocks fully covered by full-chunk calls
+            # (captured checkpoints): future hits on them replay the
+            # same compiled schedule regardless of this prompt's tail
+            pub_blocks = (hit_len + (head.size // c) * c) // ps
+            ckpts = [None] * hit_blocks + [
+                captures.get((b + 1) * ps)
+                for b in range(hit_blocks, pub_blocks)]
+            self._index.publish(prompt.tolist(),
+                                table_row[:pub_blocks], ckpts)
+        act = _Active(request=req, slot=int(slot), first=first, out=[],
+                      start_step=self._step_id, deadline=deadline)
+        self._active[int(slot)] = act
+        self.n_admissions += 1
+        self.n_prompt_tokens += p - hit_len
+        self.n_finisher_calls += 1
+        if not (req.max_new_tokens == 1 or req.eos_id is not None
+                or req.stream):
+            self._stamp(req.uid, "admitted")
+            return False
+        # needs the value now: the request may finish before any decode
+        # step, and a streaming client gets its first token at admission
+        # (TTFT does not wait for a decode horizon)
+        with span("serve.first_token_sync"):
+            act.out.append(self._flight.scalar(first))
+        self._stamp(req.uid, "admitted")
+        reason = None
+        if req.eos_id is not None and act.out[0] == req.eos_id:
+            reason = "stop"
+        elif req.max_new_tokens == 1:
+            reason = "length"
+        act.n_streamed = len(act.out)
+        if req.stream or reason is not None:
+            self._emit(req, list(act.out), reason)
+        if reason is not None:
+            self._finish(act, reason)
+        return reason is not None
 
     # -- the hot loop --------------------------------------------------------
     def _decode_fn(self, k: int) -> Callable:
@@ -709,8 +769,10 @@ class Engine:
         (the adaptive policy only ever uses 1 and ``eos_scan_every``)."""
         fn = self._decode_multi.get(k)
         if fn is None:
-            fn = jax.jit(make_decode_multi(self.model, k, **self._scope),
-                         donate_argnums=(2,))
+            step = make_decode_multi(self.model, k, **self._scope)
+            # one program per horizon, named for it in a device trace
+            step.__name__ = step.__qualname__ = f"decode_k{k}"
+            fn = jax.jit(step, donate_argnums=(2,))
             self._decode_multi[k] = fn
         return fn
 
@@ -745,9 +807,10 @@ class Engine:
         if not self._active:
             return finished
         k = self._pick_horizon()
-        block, self._tokens, self._caches, self._pos, self._term = (
-            self._decode_fn(k)(self.params, self._tokens, self._caches,
-                               self._pos, self._term))
+        with span("serve.decode", k=k):
+            block, self._tokens, self._caches, self._pos, self._term = (
+                self._decode_fn(k)(self.params, self._tokens, self._caches,
+                                   self._pos, self._term))
         self._flight.push(block)
         self._step_id += k
         self._last_horizon = k
@@ -785,9 +848,11 @@ class Engine:
         # checked at admission): keeps eviction O(1) amortized per token
         pre = {slot: len(act.out) for slot, act in self._active.items()}
         if need_full:
-            self._flush()
+            with span("serve.flush"):
+                self._flush()
         else:
-            self._flush_stream()  # completed blocks only: non-blocking
+            with span("serve.flush_stream"):
+                self._flush_stream()  # completed blocks only: non-blocking
         events = []
         for slot in list(self._active):
             act = self._active[slot]

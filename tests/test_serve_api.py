@@ -152,12 +152,13 @@ def test_status_schema_and_healthz(server):
     snap = api_client.get_status(server.host, server.port)
     assert set(snap) >= {"uptime_s", "requests", "throughput",
                          "latency_ms", "busy_slots", "engine",
-                         "prefix_cache", "decode"}
+                         "prefix_cache", "decode", "prefill"}
     assert set(snap["requests"]) == {"submitted", "finished", "rejected",
                                      "by_finish_reason"}
     assert set(snap["throughput"]) == {"tokens_total", "tokens_per_s",
                                        "requests_per_s", "steps_total"}
-    for series in ("decode_step", "ttft", "request"):
+    for series in ("engine_step", "ttft", "request", "queue_wait",
+                   "admission", "frontdoor"):
         assert set(snap["latency_ms"][series]) == {"p50", "p90", "p99"}
     eng = snap["engine"]
     assert eng["max_slots"] == MAX_SLOTS
@@ -167,7 +168,8 @@ def test_status_schema_and_healthz(server):
     # the module fixture's warmup + earlier tests have finished work
     assert snap["requests"]["finished"] >= 1
     assert snap["throughput"]["tokens_total"] >= 1
-    assert snap["latency_ms"]["decode_step"]["p50"] > 0
+    assert snap["latency_ms"]["engine_step"]["p50"] > 0
+    assert snap["latency_ms"]["admission"]["p50"] > 0
     # prefix-cache gauges (satellite: hit rate / tokens saved / occupancy)
     pc = snap["prefix_cache"]
     assert set(pc) == {"enabled", "lookups", "hits", "hit_rate",
@@ -194,6 +196,51 @@ def test_status_schema_and_healthz(server):
     assert 1 <= dec["last_horizon"] <= dec["horizon_max"]
     assert dec["host_syncs"] >= 1
     assert dec["syncs_per_token"] <= 1.0
+    # admission counters, live from Engine.prefill_stats()
+    pre = snap["prefill"]
+    assert set(pre) == {"admissions", "prompt_tokens", "chunk_calls",
+                        "tail_calls", "finisher_calls"}
+    assert pre["admissions"] == pre["finisher_calls"] >= 1
+    assert pre["prompt_tokens"] >= pre["admissions"]
+
+
+def test_request_log_is_bounded_and_feeds_the_phase_latencies():
+    """Pure-unit check of ``ServeMetrics``' per-request log: it keeps the
+    last ``window`` requests, and ``/status``' phase series read it."""
+    from repro.serve import ServeMetrics
+
+    m = ServeMetrics(window=3)
+    for i in range(5):
+        t = 10.0 * i
+        for event, dt in (("received", 0.0), ("submitted", 0.001),
+                          ("admit_start", 0.041), ("admitted", 0.091),
+                          ("first_taken", 0.092), ("first_frame", 0.094)):
+            m.stamp(f"r{i}", event, t + dt)
+    log = m.request_log()
+    assert [e["uid"] for e in log] == ["r2", "r3", "r4"]
+    lat = m.snapshot()["latency_ms"]
+    for series, ms in (("queue_wait", 40.0), ("admission", 50.0),
+                       ("frontdoor", 3.0)):
+        assert lat[series]["p50"] == pytest.approx(ms)
+
+
+def test_request_log_events_are_in_order(server):
+    """Each request's log: HTTP read <= handed to the engine thread <=
+    popped by the engine <= first token on the host <= first token taken
+    by the front door <= its SSE frame drained."""
+    from repro.serve.metrics import REQUEST_EVENTS
+
+    server.wait_idle()
+    events = list(api_client.stream_completion(
+        server.host, server.port, {"prompt": PROMPT, "max_tokens": 3}))
+    assert len(events) == 3
+    log = server.gateway.metrics.request_log()
+    uid = events[0]["id"]
+    mine = [e for e in log if e["uid"] == uid]
+    assert len(mine) == 1 and set(mine[0]) == {"uid", *REQUEST_EVENTS}
+    for entry in log:
+        times = [entry[k] for k in REQUEST_EVENTS if k in entry]
+        assert times == sorted(times), entry
 
 
 def test_status_prefix_hits_after_shared_prefix_traffic(server):

@@ -709,3 +709,138 @@ def test_scheduler_host_syncs_are_goomcheck_guarded():
            "def flush(pending):\n"
            "    return np.asarray(pending)\n")
     assert [f.rule for f in run_source(bad, "serve/steps.py")] == ["GC206"]
+
+
+# ---------------------------------------------------------------------------
+# instrumentation: admission counters, the per-request log's clock reads,
+# and the serving path's spans and program names in a profiler capture
+# ---------------------------------------------------------------------------
+PREFILL_CHUNK = 16
+
+
+@pytest.fixture(scope="module")
+def prefill_engine():
+    _, model, params = _model("olmo-1b")
+    return Engine(model, params, max_slots=1, page_len=64,
+                  chunk=PREFILL_CHUNK)
+
+
+def _admission_dispatches(p, c, hit=0):
+    """Admission of a p-token prompt whose first ``hit`` tokens come from
+    the prefix cache: full chunks, then single-token steps, up to the
+    prompt's final piece (a whole chunk when c divides p, else its last
+    token), which one fused finisher computes."""
+    head = p - (1 if p % c else c) - hit
+    return {"admissions": 1, "prompt_tokens": p - hit,
+            "chunk_calls": head // c, "tail_calls": head % c,
+            "finisher_calls": 1}
+
+
+def _delta(after, before):
+    return {k: after[k] - before[k] for k in after}
+
+
+@pytest.mark.parametrize("p", [1, 7, PREFILL_CHUNK - 1, PREFILL_CHUNK,
+                               PREFILL_CHUNK + 1, 2 * PREFILL_CHUNK + 3])
+def test_prefill_stats_count_admission_dispatches(prefill_engine, p):
+    eng = prefill_engine
+    prompt = list(map(int, _prompt(eng.model.cfg, p, seed=300 + p)))
+    before = eng.prefill_stats()
+    eng.run([Request(uid=f"p{p}", prompt=prompt, max_new_tokens=2)])
+    assert _delta(eng.prefill_stats(), before) == \
+        _admission_dispatches(p, PREFILL_CHUNK)
+
+
+def test_prefill_stats_count_a_prefix_hit_apart(prefill_engine):
+    """A repeated prompt resumes past its cached whole chunks: their
+    tokens count as saved (``prefix_stats``), not as prefill work."""
+    eng, c = prefill_engine, PREFILL_CHUNK
+    p = 2 * c + 3
+    prompt = list(map(int, _prompt(eng.model.cfg, p, seed=333)))
+    eng.run([Request(uid="first", prompt=prompt, max_new_tokens=2)])
+    before, saved = eng.prefill_stats(), eng.prefix_stats()
+    eng.run([Request(uid="again", prompt=prompt, max_new_tokens=2)])
+    hit = eng.prefix_stats()["prefill_tokens_saved"] - \
+        saved["prefill_tokens_saved"]
+    assert hit == 2 * c
+    assert _delta(eng.prefill_stats(), before) == \
+        _admission_dispatches(p, c, hit)
+
+
+def test_request_log_reads_the_clock_per_admission_not_per_token(
+        monkeypatch):
+    """With a ``ServeMetrics`` attached the engine stamps two events per
+    admission (start, first token); the decode loop adds no clock read,
+    however many tokens and dispatches it runs."""
+    from repro.serve import ServeMetrics
+    from repro.serve import metrics as metrics_mod
+
+    cfg, model, params = _model("olmo-1b")
+    reads = {"n": 0}
+    real_time = metrics_mod.time
+
+    class _Counting:
+        @staticmethod
+        def monotonic():
+            reads["n"] += 1
+            return real_time.monotonic()
+
+    def run(n_tokens):
+        eng = Engine(model, params, max_slots=2, page_len=64, chunk=4)
+        eng.metrics = ServeMetrics()
+        reads["n"] = 0
+        monkeypatch.setattr(metrics_mod, "time", _Counting)
+        eng.run([Request(uid=i, prompt=[5 + i, 6, 7], max_new_tokens=n_tokens,
+                         stream=True) for i in range(3)])
+        monkeypatch.setattr(metrics_mod, "time", real_time)
+        log = eng.metrics.request_log()
+        assert [e["uid"] for e in log] == [0, 1, 2]
+        assert all(e["admit_start"] <= e["admitted"] for e in log)
+        return reads["n"], eng.decode_stats()["dispatches"]
+
+    short, long_ = run(4), run(30)
+    assert long_[1] > short[1]        # more dispatches and tokens ...
+    assert short[0] == long_[0] == 2 * 3  # ... same reads: 2 per admission
+
+
+def test_profiler_capture_names_serve_spans_and_programs(tmp_path):
+    """A CPU profiler capture of a small engine run: the serving spans are
+    on the host plane, with the request id on ``serve.admit``, and every
+    jitted step of the serving path runs under its program name."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    cfg, model, params = _model("olmo-1b")
+    eng = Engine(model, params, max_slots=2, page_len=64, chunk=4,
+                 stream_callback=lambda uid, toks, reason: None)
+    long_prompt = list(map(int, _prompt(cfg, 11, seed=400)))
+    reqs = [Request(uid="a", prompt=long_prompt, max_new_tokens=12,
+                    stream=True),
+            Request(uid="b", prompt=[3, 1, 4, 1], max_new_tokens=3),
+            Request(uid="c", prompt=[2, 7, 1], max_new_tokens=2),
+            Request(uid="d", prompt=long_prompt, max_new_tokens=2)]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True))[-1]
+    names, admitted = set(), set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                names.add(ev.name)
+                if ev.name == "serve.admit":
+                    admitted.add(dict(ev.stats)["uid"])
+    assert {"serve.admit", "serve.prefill.chunk", "serve.prefill.tail",
+            "serve.admit.finish", "serve.first_token_sync", "serve.decode",
+            "serve.flush"} <= names
+    assert admitted == {"a", "b", "c", "d"}
+    programs = {"decode_k1", "decode_k8", "prefill_chunk", "prefill_tail",
+                "admit_chunk", "admit_tail", "fresh_cache", "snapshot_carry",
+                "gather_prefix", "clear_slot"}
+    assert {f"PjitFunction({p})" for p in programs} <= names

@@ -10,6 +10,7 @@ inputs, which computes the same mathematical function).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -118,8 +119,10 @@ def lmme_pallas(
     ``jnp.matmul``.  f32 planes only (kernel dtype).  ``variant`` selects
     the TPU-shaped kernel (sequential K grid + VMEM scratch) or the
     GPU-shaped one (in-kernel K loop + register carries, Triton lowering).
+    Where ``a`` is shared over batch dims that ``b`` varies in (a layer's
+    weights against a batch of vectors), those dims fold into the columns
+    of one product per remaining batch index.
     """
-    # Broadcast leading batch dims.
     batch = jnp.broadcast_shapes(a.shape[:-2], b.shape[:-2])
     if 0 in batch:
         # nothing to launch (associative scans combine empty slices), and a
@@ -127,24 +130,44 @@ def lmme_pallas(
         shape = batch + (a.shape[-2], b.shape[-1])
         return Goom(jnp.full(shape, -jnp.inf, jnp.float32),
                     jnp.ones(shape, jnp.float32))
-    al = jnp.broadcast_to(a.log_abs, batch + a.shape[-2:]).astype(jnp.float32)
-    asn = jnp.broadcast_to(a.sign, batch + a.shape[-2:]).astype(jnp.float32)
-    bl = jnp.broadcast_to(b.log_abs, batch + b.shape[-2:]).astype(jnp.float32)
-    bsn = jnp.broadcast_to(b.sign, batch + b.shape[-2:]).astype(jnp.float32)
+    n, d = a.shape[-2:]
+    m = b.shape[-1]
+    r = len(batch)
+    a_batch = (1,) * (r - (a.ndim - 2)) + a.shape[:-2]
+    # Fold dims: `a` is shared there (size 1) while `b` varies.  Their
+    # problems differ only in b's columns, so they ride in the columns of
+    # one (n, d) @ (d, F*m) product instead of F copies of `a`, each padded
+    # to a full tile.  Columns contract independently: same result.
+    fold = [i for i in range(r) if a_batch[i] == 1 and batch[i] > 1]
+    keep = [i for i in range(r) if i not in fold]
+    keep_shape = tuple(batch[i] for i in keep)
+    fold_shape = tuple(batch[i] for i in fold)
+    k, cols = math.prod(keep_shape), math.prod(fold_shape) * m
+    order = keep + [r] + fold + [r + 1]  # batch + (d, m) -> (keep, d, fold, m)
+
+    def planes_a(x):  # drop the size-1 fold dims: a stays one copy
+        return x.reshape((k, n, d)).astype(jnp.float32)
+
+    def planes_b(x):
+        x = jnp.broadcast_to(x, batch + (d, m)).astype(jnp.float32)
+        return x.transpose(order).reshape((k, d, cols))
 
     # Clamp block sizes to (padded) dims to avoid huge pads for small inputs.
     # GPU tiles keep every pl.dot dim >= 16 so tl.dot maps to tensor cores.
-    n, d = al.shape[-2:]
-    m = bl.shape[-1]
     if variant == "gpu":
         bn = min(block_n, max(16, 1 << (n - 1).bit_length()))
-        bm = min(block_m, max(16, 1 << (m - 1).bit_length()))
+        bm = min(block_m, max(16, 1 << (cols - 1).bit_length()))
         bd = min(block_d, max(16, 1 << (d - 1).bit_length()))
     else:
         bn = min(block_n, max(8, 1 << (n - 1).bit_length()))
-        bm = min(block_m, max(128, 1 << (m - 1).bit_length()))
+        bm = min(block_m, max(128, 1 << (cols - 1).bit_length()))
         bd = min(block_d, max(128, 1 << (d - 1).bit_length()))
 
-    out_log, out_sign = _lmme_planes(al, asn, bl, bsn, bn, bm, bd,
-                                     num_warps, num_stages, interpret, variant)
-    return Goom(out_log, out_sign)
+    out = _lmme_planes(planes_a(a.log_abs), planes_a(a.sign),
+                       planes_b(b.log_abs), planes_b(b.sign), bn, bm, bd,
+                       num_warps, num_stages, interpret, variant)
+    # (K, n, F*m) -> (keep..., n, fold..., m) -> batch + (n, m)
+    inverse = [order.index(i) for i in range(r + 2)]
+    out = tuple(x.reshape(keep_shape + (n,) + fold_shape + (m,))
+                .transpose(inverse) for x in out)
+    return Goom(*out)

@@ -4,6 +4,8 @@ Kernels run in interpret mode on CPU (the kernel body executes in Python);
 on TPU the same BlockSpecs drive the MXU/VPU directly.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,7 +15,7 @@ from hyp_compat import given, settings, st  # degrades gracefully w/o hypothesis
 from repro.core.goom import Goom, closeness, from_goom, to_goom
 from repro.core.ops import lmme_naive, lmme_reference
 from repro.core.scan import diagonal_scan
-from repro.kernels.lmme.ops import lmme_pallas
+from repro.kernels.lmme.ops import _lmme_planes, lmme_pallas
 from repro.kernels.goom_scan.goom_scan import goom_scan_kernel_call
 
 
@@ -123,6 +125,102 @@ def test_lmme_pallas_scale_invariance_property(n, d, scale):
     np.testing.assert_allclose(out1.log_abs, out0.log_abs + scale,
                                rtol=1e-5, atol=1e-4)
     np.testing.assert_array_equal(out1.sign, out0.sign)
+
+
+# A layer's per-head (H, 16, 16) matrices against one column per slot and
+# position: where `a` is shared over batch dims, they fold into b's columns.
+FOLD_H = 4
+FOLD_CASES = {
+    "decode": ((FOLD_H, 16, 16), (1, 32, FOLD_H, 16, 1)),
+    "prefill_chunk": ((FOLD_H, 16, 16), (64, 1, FOLD_H, 16, 1)),
+    "train": ((FOLD_H, 16, 16), (8, 2, FOLD_H, 16, 1)),
+    "tail": ((FOLD_H, 16, 16), (1, 1, FOLD_H, 16, 1)),  # nothing to fold
+    "no_broadcast": ((3, FOLD_H, 16, 16), (3, FOLD_H, 16, 1)),
+}
+
+
+def _fold_operands(case, scale, key=7):
+    a_shape, b_shape = FOLD_CASES[case]
+    ka, kb, ks = jax.random.split(jax.random.PRNGKey(key), 3)
+    a = to_goom(jax.random.normal(ka, a_shape))
+    b = to_goom(jax.random.normal(kb, b_shape))
+    # e+scale on a, and e+-scale column by column on b: every column keeps
+    # its own scale through the fold
+    col = scale * jax.random.rademacher(ks, b_shape[:-2] + (1, b_shape[-1]),
+                                        jnp.float32)
+    return Goom(a.log_abs + scale, a.sign), Goom(b.log_abs + col, b.sign)
+
+
+def _pallas_grids(jaxpr):
+    """The grids of every pallas_call in ``jaxpr``, nested jaxprs included."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    grids += _pallas_grids(sub)
+    return grids
+
+
+def _lmme_unfolded(a, b):
+    """The wrapper without the fold: every plane copied over the joint
+    batch, one padded problem per batch index, at the blocks ``lmme_pallas``
+    picks for n = d = 16 and m = 1."""
+    batch = jnp.broadcast_shapes(a.shape[:-2], b.shape[:-2])
+    planes = [jnp.broadcast_to(x, batch + x.shape[-2:]).astype(jnp.float32)
+              for x in (a.log_abs, a.sign, b.log_abs, b.sign)]
+    return Goom(*_lmme_planes(*planes, 16, 128, 128, 4, 2, True, "tpu"))
+
+
+@pytest.mark.parametrize("scale", [0.0, 200.0])
+@pytest.mark.parametrize("case", sorted(FOLD_CASES))
+def test_lmme_pallas_fold_matches_unfolded(case, scale):
+    a, b = _fold_operands(case, scale)
+    batch = jnp.broadcast_shapes(a.shape[:-2], b.shape[:-2])
+    got = lmme_pallas(a, b, interpret=True)
+    unfolded = _lmme_unfolded(a, b)
+    assert got.shape == batch + (a.shape[-2], b.shape[-1])
+    np.testing.assert_array_equal(got.log_abs, unfolded.log_abs)
+    np.testing.assert_array_equal(got.sign, unfolded.sign)
+    assert_goom_close(got, lmme_reference(a, b))
+
+
+def test_lmme_pallas_fold_gradients_match_reference():
+    key = jax.random.PRNGKey(8)
+    ka, kb = jax.random.split(key)
+    av = jax.random.normal(ka, (2, 16, 16))
+    bv = jax.random.normal(kb, (3, 2, 2, 16, 1))
+
+    def loss(lmme):
+        return lambda av, bv: jnp.sum(lmme(to_goom(av), to_goom(bv)).log_abs)
+
+    ga = jax.grad(loss(functools.partial(lmme_pallas, interpret=True)),
+                  argnums=(0, 1))(av, bv)
+    gr = jax.grad(loss(lmme_reference), argnums=(0, 1))(av, bv)
+    for x, y in zip(ga, gr):
+        np.testing.assert_allclose(x, y, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("case,grid", [("decode", (FOLD_H, 1, 1, 1)),
+                                       ("tail", (FOLD_H, 1, 1, 1)),
+                                       ("train", (FOLD_H, 1, 1, 1))])
+def test_lmme_fold_launches_one_problem_per_head(case, grid):
+    """The kernel grid's batch extent is H, not S*B*H: the fold engages.
+    With ``a`` materialised over the batch it is one problem per copy."""
+    from repro.core import engine
+
+    a, b = _fold_operands(case, 0.0)
+    batch = jnp.broadcast_shapes(a.shape[:-2], b.shape[:-2])
+    a_full = Goom(jnp.broadcast_to(a.log_abs, batch + a.shape[-2:]),
+                  jnp.broadcast_to(a.sign, batch + a.shape[-2:]))
+    with engine.use_backend("pallas_interpret"):
+        folded = jax.make_jaxpr(engine.lmme)(a, b).jaxpr
+        unfolded = jax.make_jaxpr(engine.lmme)(a_full, b).jaxpr
+    assert _pallas_grids(folded) == [grid]
+    assert _pallas_grids(unfolded) == [(int(np.prod(batch)), 1, 1, 1)]
 
 
 # ---------------------------------------------------------------------------

@@ -23,6 +23,11 @@ from repro.core.goom import Goom
 
 H, D = 48, 16  # goom-rnn-124m: d_model 768 = 48 heads x head_dim 16
 V5E_HBM_BYTES = 16 * 2**30
+# The shared (H, d, d) operand folds the slots and positions into the
+# columns of one product per head; materialised over them, each 16x16
+# problem pads to a 128-lane tile (138 MB at 32 slots, 428 MB for a
+# 64-token chunk).
+LMME_TEMP_BYTES = 32 * 2**20
 
 
 def _lmme(al, asn, bl, bsn):
@@ -50,6 +55,8 @@ CASES = {
     # over 8 slots -- (H, d, d) x (S, B, H, d, 1)
     "lmme_prefill_chunk": (_lmme, _pair((H, D, D), (64, 1, H, D, 1))),
     "lmme_decode": (_lmme, _pair((H, D, D), (1, 8, H, D, 1))),
+    # ... and over the serving cell's 32 slots
+    "lmme_decode_32": (_lmme, _pair((H, D, D), (1, 32, H, D, 1))),
     "diagonal_scan": (_diagonal_scan, _pair((4096, 768), (4096, 768))),
     # sequence-sharded GOOM layer: batch 8 in the state columns
     "matrix_scan": (_matrix_scan, _pair((256, H, D, D), (256, H, D, 8))),
@@ -98,3 +105,5 @@ def test_pallas_tpu_compiles_for_v5e(case, one_chip, no_compile_cache):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < V5E_HBM_BYTES
+    if case.startswith("lmme"):
+        assert mem.temp_size_in_bytes < LMME_TEMP_BYTES
